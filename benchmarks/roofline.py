@@ -1,9 +1,12 @@
 """§Roofline: three-term roofline per (arch × shape × mesh) from the dry-run
 artifacts.
 
-   compute    = HLO_FLOPs/device            / 197e12 FLOP/s   (bf16 MXU)
-   memory     = HLO_traffic_bytes/device    / 819e9  B/s      (HBM)
-   collective = collective_bytes/device     / 50e9   B/s      (ICI per link)
+   compute    = HLO_FLOPs/device            / peak FLOP/s   (bf16 MXU)
+   memory     = HLO_traffic_bytes/device    / peak HBM B/s
+   collective = collective_bytes/device     / 50e9   B/s    (ICI per link)
+
+with the peaks of the record's device kind from ``repro.devices`` (dry-run
+records target a TPU v5e pod).
 
 (The dry-run analyses the per-device partitioned module, so terms are
 per-chip seconds directly.)  The dominant term is the bottleneck; the
@@ -27,8 +30,10 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+from repro import devices
+
+#: the device kind dry-run records (which carry none) are analysed for
+DRYRUN_KIND = "TPU v5 lite"
 ICI_BW = 50e9
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "artifacts",
@@ -96,11 +101,12 @@ def analyze_record(rec: Dict) -> Optional[Dict]:
     if "skipped" in rec or "hlo" not in rec:
         return None
     h = rec["hlo"]
-    # records calibrated on a non-TPU backend carry their own peak
-    # anchors (benchmarks.figmn_dispatch / costmodel.to_roofline_records);
-    # dry-run artifacts fall back to the pod constants above
-    peak_flops = float(rec.get("peak_flops", PEAK_FLOPS))
-    hbm_bw = float(rec.get("hbm_bw", HBM_BW))
+    # calibration records (costmodel.to_roofline_records) name the device
+    # they were measured on in their "<device_kind>|jax-<version>" key
+    kind = rec["device_key"].split("|")[0] if "device_key" in rec \
+        else DRYRUN_KIND
+    peaks = devices.lookup(kind)
+    peak_flops, hbm_bw = peaks.flops, peaks.hbm_bw
     terms = {
         "compute_s": h["flops"] / peak_flops,
         "memory_s": h["traffic_bytes"] / hbm_bw,

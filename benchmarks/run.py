@@ -64,6 +64,8 @@ import sys
 import time
 import traceback
 
+from repro import compile_cache
+
 #: every registered benchmark module under benchmarks/; each exposes
 #: ``main(smoke: bool = False)`` where smoke runs a tiny-size subset.
 REGISTRY = ("figmn_scaling", "figmn_timing", "figmn_accuracy",
@@ -119,6 +121,7 @@ def _gate(name: str) -> bool:
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("names", nargs="*",
                     help=f"benchmarks to run (default: all of "

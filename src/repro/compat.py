@@ -1,62 +1,36 @@
-"""jax version compatibility shims.
+"""Shared spellings of the JAX mesh APIs every mesh-using module calls.
 
-The codebase targets the modern jax API (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh`` with ``axis_types``); the container may
-ship an older jax where shard_map still lives in ``jax.experimental`` (with
-``check_rep``) and meshes have no axis types.  Every call site routes
-through these two helpers so the difference lives in exactly one file.
+``shard_map`` with replication checking off, ``make_mesh`` with Auto-mode
+axes, and two small accessors; call sites go through these so the chosen
+options live in one file.
 """
 from __future__ import annotations
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
-
-if hasattr(jax, "shard_map"):                        # jax ≥ 0.6
-    _shard_map = jax.shard_map
-    _SM_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_KW = {"check_rep": False}
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, mesh, in_specs, out_specs, auto=frozenset()):
-    """jax.shard_map with replication checking off, any jax version."""
-    kw = dict(_SM_KW)
+    """jax.shard_map with replication checking off."""
+    kw = {"check_vma": False}
     if auto:
         kw["auto"] = frozenset(auto)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def axis_size(axis_name) -> int:
-    """STATIC size of a mapped axis, inside shard_map/pmap.
-
-    jax.lax.axis_size is missing on older jax; psum of a Python int is
-    evaluated statically there and is the portable equivalent.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """STATIC size of a mapped axis, inside shard_map/pmap."""
+    return jax.lax.axis_size(axis_name)
 
 
 def cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() as a dict on any jax version (older jax
-    returns a one-element list of per-program dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """compiled.cost_analysis() as a dict (empty when XLA reports none)."""
+    return compiled.cost_analysis() or {}
 
 
 def make_mesh(shape, axes) -> Mesh:
     """Mesh over the first prod(shape) devices with Auto-mode axes."""
     shape = tuple(shape)
-    n = int(np.prod(shape))
-    try:                                             # jax ≥ 0.6
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(shape))
-    except ImportError:
-        devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return Mesh(devs, tuple(axes))
+    return jax.make_mesh(shape, tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))

@@ -429,8 +429,17 @@ def _predict_sparse_jit(cfg: FIGMNConfig, f: _CondFactors, sp: Array,
     # C covering the pool ⇒ the sorted shortlist IS the identity
     # permutation: skip the bound pass + gather and run the shared dense
     # block body — bit-identity with predict_batch by construction (and
-    # strictly faster than gathering every row).
-    block = block_dense if c >= kpool else block_sparse
+    # strictly faster than gathering every row).  C covering only the
+    # ACTIVE slots drops no mass either, but the gathered (B, C) einsums
+    # may reduce in another order than the dense (K,) ones: run the dense
+    # body there too, so C ≥ active K is bit-identical at any K.
+    if c >= kpool:
+        block = block_dense
+    else:
+        covered = jnp.sum(active) <= c
+
+        def block(xb: Array) -> Array:
+            return jax.lax.cond(covered, block_dense, block_sparse, xb)
     return _map_blocks(block, xs_in, block_b)
 
 
@@ -445,16 +454,12 @@ def predict_batch_sparse(cfg: FIGMNConfig, state: FIGMNState, xs_in: Array,
     Mahalanobis, masked posterior) runs on the C gathered rows only.
 
     Exactness contract (tests/test_api.py, same pattern as the shortlisted
-    score/fit paths): with C covering the pool the shortlist is the
-    identity permutation and the SAME dense block body runs —
-    BIT-IDENTICAL to ``predict_batch`` by construction, at any batch size.
-    With active K ≤ C < K the bound pass selects every live component
-    (its -inf masking guarantees actives outrank the inactive tail), so
-    no posterior mass is dropped: bit-identical at golden-stream scale
-    (pinned), float-tolerance-identical in general (the gathered einsums
-    reduce in a different order, which large Mahalanobis distances
-    amplify).  Below active K the truncation drops only numerically-zero
-    posterior tail mass.
+    score/fit paths): whenever C covers the active slots (C ≥ active K)
+    the SAME dense block body runs, so the result is BIT-IDENTICAL to
+    ``predict_batch`` at any batch size; with C covering the whole pool
+    that is decided when tracing, below it by a ``lax.cond`` on the
+    active count (pinned on the golden streams).  Below active K the
+    truncation drops only numerically-zero posterior tail mass.
     """
     require_nonempty(state)
     kpool = int(state.active.shape[0])

@@ -73,6 +73,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.types import Array, FIGMNConfig, FIGMNState
@@ -154,7 +155,10 @@ class FleetConfig:
                        RpcConfig defaults; an unset ingest_silence_s is
                        resolved from the supervisor's heartbeat timeout
                        so the watchdog always quarantines before the
-                       wire kills a silent worker).
+                       wire kills a silent worker).  Process placement
+                       runs on the CPU only: a parent that has started
+                       an accelerator backend holds the chip, and the
+                       workers it spawns could not reach it.
     """
     n_replicas: int = 2
     router: str = "round_robin"
@@ -189,6 +193,12 @@ class FleetCoordinator:
             raise ValueError(f"placement must be 'thread' or 'process', "
                              f"got {fcfg.placement!r}")
         self._remote = fcfg.placement == "process"
+        if self._remote and jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"placement='process' needs the CPU backend, but this "
+                f"process runs on {jax.default_backend()!r}: a chip "
+                f"belongs to the one process that opened it, so worker "
+                f"processes cannot share it.  Use placement='thread'.")
         self._rpc = self._resolve_rpc()
         self.router = ShardRouter(
             RouterConfig(policy=fcfg.router, seed=fcfg.router_seed),

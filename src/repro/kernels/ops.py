@@ -3,8 +3,8 @@
 Handles: padding D up to MXU-aligned tiles (zero-padding is exact for all
 three ops — padded rows/cols contribute 0 to quadratic forms and matvecs and
 are sliced off afterwards), tile-size selection under a VMEM budget, and
-interpret-mode fallback on CPU (the container has no TPU; ``interpret=True``
-executes the kernel body in Python for correctness validation).
+interpret mode on the CPU platform (``interpret=True`` executes the kernel
+body on the host for correctness validation).
 """
 from __future__ import annotations
 
@@ -21,7 +21,7 @@ _VMEM_BUDGET = 4 * 1024 * 1024  # conservative per-operand bytes
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _pad_dim(d: int) -> int:

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.api import Mixture, MixtureSpec
 from repro.checkpoint import CheckpointManager
 from repro.core import figmn
@@ -39,6 +39,7 @@ from repro.stream import DriftConfig, LifecycleConfig, RuntimeConfig, costmodel
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
     ap.add_argument("--smoke", action="store_true")
@@ -230,6 +231,7 @@ def main() -> None:
     # the absolute residual is small in a tight regime.
     recon, rvar = monitor.predict(feats[:, :-1], targets=[gcfg.dim - 1],
                                   return_var=True)
+    print(f"FIGMN OOD monitor: {monitor!r}")
     resid = float(jnp.mean(jnp.abs(recon[:, 0] - feats[:, -1])))
     zscore = float(jnp.mean(jnp.abs(recon[:, 0] - feats[:, -1])
                             / jnp.sqrt(jnp.maximum(rvar[:, 0], 1e-12))))

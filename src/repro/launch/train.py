@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.checkpoint import CheckpointManager
 from repro.data.tokens import SyntheticTokens, TokenPipelineConfig
 from repro.distributed.sharding import mesh_rules
@@ -35,6 +35,7 @@ from repro.train import trainer
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
     ap.add_argument("--smoke", action="store_true",

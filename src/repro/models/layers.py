@@ -230,7 +230,7 @@ def attention_trainpath(q: Array, k: Array, v: Array, q_pos: Array,
     idx = jnp.arange(h) // g
     k = jnp.take(k, idx, axis=2)                    # (B, S, H, hd)
     v = jnp.take(v, idx, axis=2)
-    interp = jax.default_backend() != "tpu"
+    interp = jax.default_backend() == "cpu"
     win = jnp.asarray(window, jnp.int32)
 
     mesh = active_mesh()

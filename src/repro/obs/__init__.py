@@ -19,8 +19,9 @@ snapshot staleness) and the ``api.Mixture`` entry points.
                JSON metric dumps, and the shared ``to_json`` envelope
                (schema_version) every BENCH_*/telemetry file goes through
   prof.py      profiling harness: compile-excluded donation-safe wall
-               timing, HLO-derived roofline terms, per-backend peak
-               anchors — feeds ``stream.costmodel``'s calibration
+               timing, HLO-derived roofline terms against the
+               ``repro.devices`` peaks — feeds ``stream.costmodel``'s
+               calibration
 
 The serving→autoscaler loop closes through here: ``ScoringFrontend``
 records request latency into a mergeable histogram, the coordinator diffs

@@ -20,46 +20,24 @@ discipline instead of re-inventing it per script:
                      to plain HLO (e.g. Pallas interpret-mode bodies) —
                      a calibration cell without a prediction is still a
                      valid measurement.
-  ``roofline_terms`` — fold an hlo_cost dict against per-backend peak
-                     numbers into the classic two-term roofline:
-                     predicted_s = max(flops/peak, bytes/bw), tagged with
-                     the binding term.  Peak numbers for TPU match
-                     benchmarks/roofline.py; CPU/GPU entries are coarse
-                     order-of-magnitude anchors — the cost model's path
-                     CHOICES come from measured medians, predictions only
-                     attribute *why* a path wins.
+  ``roofline_terms`` — fold an hlo_cost dict against the device kind's
+                     peaks (``repro.devices``, the table
+                     benchmarks/roofline.py reads too) into the classic
+                     two-term roofline: predicted_s = max(flops/peak,
+                     bytes/bw), tagged with the binding term — the cost
+                     model's path CHOICES come from measured medians,
+                     predictions only attribute *why* a path wins.
 """
 from __future__ import annotations
 
-import dataclasses
 import statistics
 import time
 from typing import Callable, Dict, Optional, Sequence
 
 import jax
 
+from repro import devices
 from repro.distributed import hlo_analysis
-
-
-@dataclasses.dataclass(frozen=True)
-class DevicePeaks:
-    """Peak compute / memory-bandwidth anchors for one backend."""
-    name: str
-    flops: float     # FLOP/s
-    hbm_bw: float    # bytes/s
-
-
-#: per-backend anchors; "tpu" matches benchmarks/roofline.py (bf16 MXU +
-#: HBM), "cpu"/"gpu" are coarse single-device anchors for attribution.
-PEAKS = {
-    "tpu": DevicePeaks("tpu", flops=197e12, hbm_bw=819e9),
-    "gpu": DevicePeaks("gpu", flops=60e12, hbm_bw=1500e9),
-    "cpu": DevicePeaks("cpu", flops=1e11, hbm_bw=3e10),
-}
-
-
-def backend_peaks(backend: str) -> DevicePeaks:
-    return PEAKS.get(backend, PEAKS["cpu"])
 
 
 def median_time(fn: Callable, make_args: Callable[[], Sequence],
@@ -97,12 +75,13 @@ def hlo_cost(fn: Callable, *args) -> Optional[Dict[str, float]]:
         return None
 
 
-def roofline_terms(hlo: Optional[Dict[str, float]], backend: str
+def roofline_terms(hlo: Optional[Dict[str, float]], device_kind: str
                    ) -> Optional[Dict[str, float]]:
-    """→ {compute_s, memory_s, predicted_s, bottleneck} or None."""
+    """→ {compute_s, memory_s, predicted_s, bottleneck} or None; an
+    unknown ``device_kind`` raises ``devices.UnknownDeviceError``."""
     if not hlo:
         return None
-    peaks = backend_peaks(backend)
+    peaks = devices.lookup(device_kind)
     compute_s = float(hlo.get("flops", 0.0)) / peaks.flops
     memory_s = float(hlo.get("traffic_bytes", 0.0)) / peaks.hbm_bw
     bottleneck = "compute" if compute_s >= memory_s else "memory"

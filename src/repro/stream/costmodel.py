@@ -20,20 +20,18 @@ the guess as the bit-compatible fallback:
     with no table (or no cells for this device key) the decision IS
     ``ingest.select_path``'s heuristic, bit-compatibly; with a table, the
     path with the smallest measured per-point seconds wins among the
-    SAFE candidates — the vmem candidacy guard (exact update mode,
-    working set ≤ VMEM budget, TPU backend) is a launch-correctness
-    constraint and survives calibration, so an oversized working set can
-    never select "vmem" no matter what a table claims.
+    SAFE candidates — the vmem candidacy guard (exact update mode, the
+    kernel's VMEM footprint ≤ the device's VMEM, TPU backend) is a
+    launch-correctness constraint and survives calibration, so an
+    oversized working set can never select "vmem" no matter what a table
+    claims.
   * ``resolve_path`` additionally exports the decision layer:
     ``figmn_dispatch_decisions_total{path,reason}``,
     ``figmn_vmem_budget_bytes``, ``figmn_dispatch_predicted_seconds`` and
     a ``dispatch.resolve`` span in the obs trace stream.
 
-The VMEM budget itself stops being a constant where the backend can be
-asked: ``resolve_vmem_budget`` queries the device's memory stats for a
-VMEM capacity and only then falls back to ``ingest.DEFAULT_VMEM_BUDGET``
-(CPU's ``memory_stats()`` is None ⇒ the constant, which is what keeps
-no-table CPU decisions bit-identical to the PR-6 heuristic).
+The VMEM budget comes from the device's entry in ``repro.devices``, keyed
+by ``device_kind``; an unknown kind raises instead of guessing.
 """
 from __future__ import annotations
 
@@ -46,8 +44,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import devices
 from repro.core import figmn, shortlist
 from repro.core.types import FIGMNConfig
+from repro.kernels import figmn_stream
 from repro.obs import export as obs_export
 from repro.obs import prof
 from repro.obs import registry as obs_registry
@@ -58,11 +58,6 @@ from repro.stream import ingest
 #: refuses versions it does not know (misparsing a table would silently
 #: redirect production dispatch).
 TABLE_VERSION = 1
-
-#: device memory_stats keys that plausibly expose a VMEM-like capacity,
-#: in preference order (backend-dependent; absent on CPU).
-_VMEM_STAT_KEYS = ("vmem_size_bytes", "vmem_bytes_limit", "vmem_size")
-
 
 def resolve_backend(device: Optional[str] = None) -> str:
     """The backend a dispatch decision is for: an explicit platform name
@@ -81,36 +76,21 @@ def device_key(device: Optional[str] = None) -> str:
     re-resolves from its own entries — or falls back to the heuristic —
     instead of replaying a stale decision.
     """
-    backend = resolve_backend(device)
-    try:
-        kind = jax.devices(backend)[0].device_kind
-    except Exception:
-        kind = backend
+    kind = devices.device_kind(resolve_backend(device))
     return f"{kind}|jax-{jax.__version__}"
 
 
 def resolve_vmem_budget(explicit: Optional[int] = None,
                         device: Optional[str] = None) -> Tuple[int, str]:
-    """→ (bytes, source) with source ∈ {"config", "device", "default"}.
+    """→ (bytes, source) with source ∈ {"config", "device"}.
 
-    An explicit budget always wins (operator override).  Otherwise ask the
-    device: backends that expose a VMEM-like capacity in
-    ``memory_stats()`` get a measured budget; the guessed 12 MiB constant
-    is the FINAL fallback only (and the one CPU takes, where
-    ``memory_stats()`` is None — keeping no-table CPU decisions
-    bit-identical to the constant-budget heuristic).
+    An explicit budget always wins (operator override).  Otherwise the
+    device's entry in ``repro.devices`` (0 on the CPU, which has no VMEM);
+    an unknown device kind raises.
     """
     if explicit is not None:
         return int(explicit), "config"
-    try:
-        stats = jax.devices(resolve_backend(device))[0].memory_stats()
-    except Exception:
-        stats = None
-    if stats:
-        for key in _VMEM_STAT_KEYS:
-            if key in stats and int(stats[key]) > 0:
-                return int(stats[key]), "device"
-    return ingest.DEFAULT_VMEM_BUDGET, "default"
+    return ingest.vmem_budget_for(resolve_backend(device)), "device"
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +231,7 @@ class DispatchDecision:
     device_key: str
     backend: str
     vmem_budget: int
-    vmem_source: str          # "config" | "device" | "default"
+    vmem_source: str          # "config" | "device"
     per_point_s: Optional[float] = None
     predicted_s: Optional[float] = None   # HLO roofline seconds (cell)
     measured_s: Optional[float] = None
@@ -260,12 +240,11 @@ class DispatchDecision:
     candidates: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
-def _vmem_candidate_ok(cfg: FIGMNConfig, budget: int, backend: str) -> bool:
+def _vmem_candidate_ok(cfg: FIGMNConfig, budget: int, backend: str,
+                       chunk: int) -> bool:
     """The launch-correctness guard the vmem kernel requires — identical
     to the heuristic's condition and NOT overridable by a table."""
-    working_set = cfg.kmax * cfg.dim * cfg.dim * 4
-    return (cfg.update_mode == "exact" and working_set <= budget
-            and backend == "tpu")
+    return backend == "tpu" and ingest.vmem_fits(cfg, budget, chunk)
 
 
 def decide(cfg: FIGMNConfig, *, requested: str = "auto", chunk: int = 256,
@@ -283,7 +262,8 @@ def decide(cfg: FIGMNConfig, *, requested: str = "auto", chunk: int = 256,
     backend = resolve_backend(device)
     budget, source = resolve_vmem_budget(vmem_budget, device)
     heuristic = ingest.select_path(cfg, vmem_budget=budget,
-                                   requested=requested, device=backend)
+                                   requested=requested, device=backend,
+                                   chunk=chunk)
     dkey = device_key(device)
     base = dict(heuristic_path=heuristic, device_key=dkey, backend=backend,
                 vmem_budget=budget, vmem_source=source)
@@ -295,7 +275,7 @@ def decide(cfg: FIGMNConfig, *, requested: str = "auto", chunk: int = 256,
     candidates = ["scan"]
     if cfg.shortlist_c > 0:
         candidates.append("sparse")
-    if _vmem_candidate_ok(cfg, budget, backend):
+    if _vmem_candidate_ok(cfg, budget, backend, chunk):
         candidates.append("vmem")
     found: Dict[str, Dict] = {}
     for path in candidates:
@@ -336,8 +316,8 @@ def resolve_path(cfg: FIGMNConfig, *, requested: str = "auto",
                 {"path": d.path, "reason": d.reason}).inc()
     reg.gauge("figmn_vmem_budget_bytes",
               "VMEM budget the kernel-launch guard compares against "
-              "(source: config override, device query, or the 12 MiB "
-              "default)").set(d.vmem_budget)
+              "(source: config override or the device's table entry)"
+              ).set(d.vmem_budget)
     if d.per_point_s is not None:
         reg.gauge("figmn_dispatch_predicted_seconds",
                   "cost-table expected seconds for one chunk on the "
@@ -435,7 +415,8 @@ def _copy_state(state):
 
 
 def _mk_cell(kind: str, path: str, k: int, d: int, c: int, n: int,
-             measured_s: float, hlo: Optional[Dict], backend: str) -> Dict:
+             measured_s: float, hlo: Optional[Dict], device_kind: str
+             ) -> Dict:
     cell = {"kind": kind, "path": path, "k": int(k), "d": int(d),
             "c": int(c), "n": int(n), "measured_s": float(measured_s),
             "per_point_s": float(measured_s) / max(int(n), 1),
@@ -444,7 +425,7 @@ def _mk_cell(kind: str, path: str, k: int, d: int, c: int, n: int,
                     if hlo else None),
             "compute_s": None, "memory_s": None, "predicted_s": None,
             "bottleneck": None}
-    terms = prof.roofline_terms(hlo, backend)
+    terms = prof.roofline_terms(hlo, device_kind)
     if terms:
         cell.update(terms)
     return cell
@@ -469,6 +450,7 @@ def calibrate(grid: Sequence = DEFAULT_GRID, *,
 
     backend = resolve_backend(device)
     dkey = device_key(device)
+    dev = devices.device_kind(backend)
     if include_vmem is None:
         include_vmem = backend == "tpu"
     table = CostTable(meta={"backend": backend, "device_key": dkey,
@@ -499,7 +481,7 @@ def calibrate(grid: Sequence = DEFAULT_GRID, *,
                 hlo = prof.hlo_cost(
                     lambda s, x: figmn.fit(cfg_dense, s, x), warm, xc)
                 table.add_cell(dkey, _mk_cell(
-                    "ingest", "scan", kmax, d, 0, n, t, hlo, backend))
+                    "ingest", "scan", kmax, d, 0, n, t, hlo, dev))
 
                 for c in cs:
                     cfg_c = dataclasses.replace(cfg_dense, shortlist_c=c)
@@ -511,17 +493,17 @@ def calibrate(grid: Sequence = DEFAULT_GRID, *,
                         lambda s, x: shortlist.fit_sparse(cfg_c, s, x),
                         warm, xc)
                     table.add_cell(dkey, _mk_cell(
-                        "ingest", "sparse", kmax, d, c, n, t, hlo, backend))
+                        "ingest", "sparse", kmax, d, c, n, t, hlo, dev))
 
                 if include_vmem and _vmem_candidate_ok(
                         cfg_dense, resolve_vmem_budget(None, device)[0],
-                        backend):
+                        backend, n):
                     t = prof.median_time(
                         ingest.fit_chunk_vmem,
                         lambda: (cfg_dense, _copy_state(warm), xc),
                         repeats=repeats)
                     table.add_cell(dkey, _mk_cell(
-                        "ingest", "vmem", kmax, d, 0, n, t, None, backend))
+                        "ingest", "vmem", kmax, d, 0, n, t, None, dev))
 
         # serving reads: dense vs sparse score, dense vs sparse predict
         with span("costmodel.calibrate_serve", k=kmax, d=d, n=n_serve):
@@ -532,7 +514,7 @@ def calibrate(grid: Sequence = DEFAULT_GRID, *,
                 lambda s, x: figmn.score_batch(cfg_dense, s, x),
                 warm, serve)
             table.add_cell(dkey, _mk_cell(
-                "score", "dense", kmax, d, 0, n_serve, t, hlo, backend))
+                "score", "dense", kmax, d, 0, n_serve, t, hlo, dev))
 
             t = prof.median_time(
                 inference.predict_batch,
@@ -543,7 +525,7 @@ def calibrate(grid: Sequence = DEFAULT_GRID, *,
                     inference._factors_jit(cfg_dense, s, targets),
                     s.sp, s.active, x), warm, serve_in)
             table.add_cell(dkey, _mk_cell(
-                "predict", "dense", kmax, d, 0, n_serve, t, hlo, backend))
+                "predict", "dense", kmax, d, 0, n_serve, t, hlo, dev))
 
             for c in cs:
                 cfg_c = dataclasses.replace(cfg_dense, shortlist_c=c)
@@ -555,7 +537,7 @@ def calibrate(grid: Sequence = DEFAULT_GRID, *,
                     warm, serve)
                 table.add_cell(dkey, _mk_cell(
                     "score", "sparse", kmax, d, c, n_serve, t, hlo,
-                    backend))
+                    dev))
 
                 t = prof.median_time(
                     inference.predict_batch_sparse,
@@ -567,7 +549,7 @@ def calibrate(grid: Sequence = DEFAULT_GRID, *,
                         s.sp, s.active, x, c), warm, serve_in)
                 table.add_cell(dkey, _mk_cell(
                     "predict", "sparse", kmax, d, c, n_serve, t, hlo,
-                    backend))
+                    dev))
 
         if verbose:
             print(f"calibrated K={kmax} D={d} Cs={tuple(cs)} "
@@ -594,10 +576,7 @@ def to_roofline_records(table: CostTable,
                 continue
             h = dict(cell["hlo"])
             h.setdefault("coll_bytes_total", 0.0)
-            backend = table.meta.get("backend", "cpu")
-            peaks = prof.backend_peaks(backend)
             recs.append({
-                "peak_flops": peaks.flops, "hbm_bw": peaks.hbm_bw,
                 "arch": "figmn-path",
                 "shape": (f"{cell['kind']}-{cell['path']}"
                           f"_k{cell['k']}_d{cell['d']}"
@@ -625,7 +604,8 @@ def explain(cfg: FIGMNConfig, *, requested: str = "auto", chunk: int = 256,
         f"(K={cfg.kmax} D={cfg.dim} C={cfg.shortlist_c} chunk={chunk})",
         f"  device_key: {d.device_key} (backend={d.backend})",
         f"  vmem_budget: {d.vmem_budget} bytes ({d.vmem_source}) — "
-        f"working set {cfg.kmax * cfg.dim * cfg.dim * 4} bytes",
+        f"kernel needs {figmn_stream.vmem_bytes(cfg.kmax, cfg.dim, chunk)} "
+        f"bytes",
         f"  heuristic counterfactual: {d.heuristic_path!r}"
         + (" (table overrode it)" if d.path != d.heuristic_path else
            " (agrees)"),

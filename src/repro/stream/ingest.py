@@ -24,11 +24,10 @@ consumes a chunk.  Two bodies exist:
 
 ``select_path`` picks between them: the sparse body whenever the config
 enables a shortlist (cfg.shortlist_c > 0 — the biggest K-scaling lever),
-else the vmem kernel under a VMEM-budget heuristic (only profitable — and
-only correct to launch — when the working set K·D²·4B fits the budget, the
-update mode is the PSD-safe "exact" one, and we are actually on a TPU; in
-interpret mode the kernel is a correctness path, not a fast path), else
-the scan reference.
+else the vmem kernel when the platform is a TPU, the update mode is the
+PSD-safe "exact" one and everything the kernel holds in VMEM
+(``figmn_stream.vmem_bytes`` at the chunk size) fits the device's VMEM
+(``repro.devices``), else the scan reference.
 """
 from __future__ import annotations
 
@@ -39,31 +38,44 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import devices
 from repro.core import figmn, shortlist
 from repro.core.types import Array, FIGMNConfig, FIGMNState, chi2_quantile
 from repro.kernels import figmn_stream
 
-#: Conservative per-core VMEM available to the resident kernel (bytes).
-DEFAULT_VMEM_BUDGET = 12 * 2 ** 20
+
+def vmem_budget_for(platform: Optional[str] = None) -> int:
+    """VMEM bytes the resident kernel may use on ``platform``'s device
+    (None: the default backend), from ``repro.devices``; 0 where the
+    device has no VMEM.  An unknown device kind raises."""
+    return devices.lookup(devices.device_kind(platform)).vmem_bytes
+
+
+def vmem_fits(cfg: FIGMNConfig, budget: int, chunk: int) -> bool:
+    """The launch guard of the vmem kernel: exact update mode, and its
+    VMEM footprint at this chunk size within ``budget``."""
+    return (cfg.update_mode == "exact"
+            and figmn_stream.vmem_bytes(cfg.kmax, cfg.dim, chunk) <= budget)
 
 
 def select_path(cfg: FIGMNConfig, *,
-                vmem_budget: Optional[int] = DEFAULT_VMEM_BUDGET,
+                vmem_budget: Optional[int] = None,
                 requested: str = "auto",
-                device: Optional[str] = None) -> str:
+                device: Optional[str] = None,
+                chunk: int = 256) -> str:
     """Choose the per-chunk dispatch path ("scan" | "vmem" | "sparse").
 
     requested: "scan"/"vmem"/"sparse" force a path; "auto" applies the
     heuristic.  A forced "sparse" requires cfg.shortlist_c > 0 (the width
     is a config property, not a runtime knob — jitted shapes depend on it).
-    device: explicit backend platform ("cpu"/"gpu"/"tpu") the decision is
-    for; None keys off the process default backend (the historical
-    behaviour).  This is the pure HEURISTIC; the measured, table-driven
-    resolution lives in ``stream.costmodel`` and falls back here
-    bit-compatibly when no calibration table exists.
+    device: explicit backend platform ("cpu"/"tpu") the decision is for;
+    None keys off the process default backend.  vmem_budget: bytes the
+    kernel may use; None takes the device's entry in ``repro.devices``.
+    chunk: points per dispatch (the kernel holds the chunk in VMEM).
+    This is the pure HEURISTIC; the measured, table-driven resolution
+    lives in ``stream.costmodel`` and falls back here bit-compatibly when
+    no calibration table exists.
     """
-    if vmem_budget is None:
-        vmem_budget = DEFAULT_VMEM_BUDGET
     if requested == "sparse" or (requested == "auto"
                                  and cfg.shortlist_c > 0):
         if cfg.shortlist_c <= 0:
@@ -74,13 +86,12 @@ def select_path(cfg: FIGMNConfig, *,
         return requested
     if requested != "auto":
         raise ValueError(f"unknown path {requested!r}")
-    working_set = cfg.kmax * cfg.dim * cfg.dim * 4
     backend = device if device else jax.default_backend()
-    if (cfg.update_mode == "exact"
-            and working_set <= vmem_budget
-            and backend == "tpu"):
-        return "vmem"
-    return "scan"
+    if backend != "tpu":
+        return "scan"
+    if vmem_budget is None:
+        vmem_budget = vmem_budget_for(backend)
+    return "vmem" if vmem_fits(cfg, vmem_budget, chunk) else "scan"
 
 
 NONFINITE_POLICIES = ("drop", "reject", "raise")
@@ -186,7 +197,9 @@ def fit_chunk_sparse(cfg: FIGMNConfig, state: FIGMNState, xc: Array,
 
 def fit_chunk_vmem(cfg: FIGMNConfig, state: FIGMNState, xc: Array
                    ) -> Tuple[FIGMNState, Array]:
-    """VMEM-resident path: whole chunk in one pallas_call.
+    """VMEM-resident path: whole chunk in one pallas_call, compiled for
+    the default device with its VMEM limit from ``repro.devices`` (the
+    kernel body is interpreted on the CPU).
 
     Creation events are no-ops inside the kernel (gate-failing points leave
     the state untouched); the caller collects them via ``chunk_stats`` for
@@ -198,12 +211,13 @@ def fit_chunk_vmem(cfg: FIGMNConfig, state: FIGMNState, xc: Array
     n = int(xc.shape[0])
     thresh = jnp.asarray(
         [float(chi2_quantile(cfg.dim, 1.0 - cfg.beta))], jnp.float32)
+    on_cpu = jax.default_backend() == "cpu"
     mu, lam, logdet, sp, nacc = figmn_stream.figmn_stream_pallas(
         xc.astype(jnp.float32), state.mu.astype(jnp.float32),
         state.lam.astype(jnp.float32), state.logdet.astype(jnp.float32),
         state.sp.astype(jnp.float32), state.active.astype(jnp.int32),
-        thresh, dim=cfg.dim, n_points=n,
-        interpret=jax.default_backend() != "tpu")
+        thresh, dim=cfg.dim, n_points=n, interpret=on_cpu,
+        vmem_limit=None if on_cpu else vmem_budget_for())
     dt = cfg.dtype
     new = FIGMNState(
         mu=mu.astype(dt), lam=lam.astype(dt), logdet=logdet.astype(dt),

@@ -51,11 +51,10 @@ class RuntimeConfig:
     checkpoint_dir:   enables checkpoint/resume; None disables.
     checkpoint_every: chunks between periodic saves (0 ⇒ only final/fork).
     vmem_budget:      bytes assumed available for the VMEM-resident
-                      kernel; None (the default) resolves it from the
-                      device's own memory stats where the backend exposes
-                      a VMEM capacity, falling back to the 12 MiB
-                      constant (costmodel.resolve_vmem_budget).
-    device:           explicit backend platform ("cpu"/"gpu"/"tpu") the
+                      kernel; None (the default) takes the device kind's
+                      entry in ``repro.devices``
+                      (costmodel.resolve_vmem_budget).
+    device:           explicit backend platform ("cpu"/"tpu") the
                       dispatch decision is for; None keys off the process
                       default backend.  A checkpoint restored on
                       different hardware re-resolves against the new

@@ -10,10 +10,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import devices
 from repro.core import figmn, inference
 from repro.core.types import FIGMNConfig
 from repro.distributed import hlo_analysis
+from repro.kernels import figmn_stream
+from repro.obs import prof
 from repro.stream import costmodel, ingest
+
+
+@pytest.fixture
+def tpu_kind(monkeypatch):
+    """Decisions *for* a v5e made on this CPU host: the "tpu" platform
+    reports the v5e's device kind, every other platform its own."""
+    real = devices.device_kind
+    monkeypatch.setattr(devices, "device_kind", lambda platform=None:
+                        "TPU v5 lite" if platform == "tpu" else real(platform))
 
 
 def _cfg(k=16, d=8, c=0, **kw):
@@ -90,22 +102,23 @@ def test_from_any_accepts_none_table_path_dict(tmp_path):
 
 # -- no-table fallback: bit-compat with the PR-6 heuristic ----------------
 
-def _pr6_heuristic(cfg, vmem_budget, requested, backend):
+def _pr6_heuristic(cfg, vmem_budget, requested, backend, chunk=256):
     """The pre-costmodel select_path, reimplemented verbatim: the contract
-    the no-table fallback is pinned to."""
+    the no-table fallback is pinned to (the working set is what the
+    kernel holds in VMEM at the chunk size)."""
     if requested == "sparse" or (requested == "auto"
                                  and cfg.shortlist_c > 0):
         return "sparse"
     if requested in ("scan", "vmem"):
         return requested
-    working_set = cfg.kmax * cfg.dim * cfg.dim * 4
+    working_set = figmn_stream.vmem_bytes(cfg.kmax, cfg.dim, chunk)
     if (cfg.update_mode == "exact" and working_set <= vmem_budget
             and backend == "tpu"):
         return "vmem"
     return "scan"
 
 
-def test_no_table_decisions_pin_pr6_heuristic_across_grid():
+def test_no_table_decisions_pin_pr6_heuristic_across_grid(tpu_kind):
     budgets = (None, 1024, 12 * 2 ** 20, 1 << 30)
     cfgs = [_cfg(16, 8), _cfg(16, 8, c=4), _cfg(512, 64),
             _cfg(512, 64, c=16), _cfg(64, 16, update_mode="joseph")]
@@ -130,12 +143,27 @@ def test_no_table_decisions_pin_pr6_heuristic_across_grid():
             assert d.reason in ("forced", "heuristic")
 
 
-def test_cpu_vmem_budget_falls_back_to_constant():
-    # CPU exposes no VMEM-like capacity ⇒ the guessed constant survives
-    # as the final fallback and no-table CPU decisions stay bit-identical
-    budget, source = costmodel.resolve_vmem_budget(None, "cpu")
-    assert (budget, source) == (ingest.DEFAULT_VMEM_BUDGET, "default")
+def test_cpu_vmem_budget_falls_back_to_constant(tpu_kind):
+    # the budget is the device kind's table entry: the CPU has no VMEM,
+    # the v5e its 128 MiB; an explicit budget still wins
+    assert costmodel.resolve_vmem_budget(None, "cpu") == (0, "device")
+    assert costmodel.resolve_vmem_budget(None, "tpu") == (
+        devices.lookup("TPU v5 lite").vmem_bytes, "device")
     assert costmodel.resolve_vmem_budget(4096, "cpu") == (4096, "config")
+
+
+@pytest.mark.parametrize("lookup", ["peaks", "vmem_budget"])
+def test_unknown_device_kind_raises(monkeypatch, lookup):
+    """Neither a roofline peak nor the kernel's VMEM budget is ever
+    guessed for a device the table does not know."""
+    monkeypatch.setattr(devices, "device_kind",
+                        lambda platform=None: "TPU v99")
+    with pytest.raises(devices.UnknownDeviceError, match="TPU v99"):
+        if lookup == "peaks":
+            prof.roofline_terms({"flops": 1.0, "traffic_bytes": 1.0},
+                                devices.device_kind())
+        else:
+            costmodel.resolve_vmem_budget(None, "tpu")
 
 
 # -- determinism ----------------------------------------------------------
@@ -205,11 +233,11 @@ def test_no_matching_cells_falls_back_with_reason():
     assert d.reason == "no_table_entry"
 
 
-def test_oversized_working_set_never_selects_vmem():
+def test_oversized_working_set_never_selects_vmem(tpu_kind):
     """The launch-correctness guard survives calibration: a table claiming
     vmem is fastest cannot launch a kernel whose working set exceeds the
     budget (or a non-TPU backend)."""
-    cfg = _cfg(512, 64, update_mode="exact")    # 512·64²·4B = 8 MiB
+    cfg = _cfg(512, 64, update_mode="exact")    # kernel needs ≈ 41 MB
     cells = [_cell("ingest", "vmem", 512, 64, 0, 128, 1e-9),
              _cell("ingest", "scan", 512, 64, 0, 128, 1e-3)]
     lying = _table(cells)

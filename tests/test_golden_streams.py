@@ -11,8 +11,10 @@ final FIGMNState under each path, and the tier-1 suite fails on the first
 bit that moves.
 
 Digests are platform-pinned to CPU (conftest sets JAX_PLATFORMS=cpu), the
-backend every CI and container run uses.  After an INTENTIONAL numeric
-change, regenerate and commit:
+backend every CI and container run uses, and to the jax version recorded
+in digests.json (XLA may reorder float reductions between releases).
+After an INTENTIONAL numeric change or a jax upgrade, regenerate and
+commit:
 
     PYTHONPATH=src python tests/test_golden_streams.py --regen
 
@@ -22,6 +24,7 @@ import hashlib
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -75,7 +78,7 @@ def _run(x: np.ndarray, path: str, chunk: int):
 
 def regen() -> dict:
     os.makedirs(GOLDEN_DIR, exist_ok=True)
-    doc = {"fixtures": {}}
+    doc = {"jax_version": jax.__version__, "fixtures": {}}
     for name, n, d, modes, chunk in FIXTURES:
         x = _make_stream(name, n, d, modes)
         np.savez(os.path.join(GOLDEN_DIR, f"{name}.npz"), x=x)
@@ -113,8 +116,10 @@ def test_ingest_paths_reproduce_golden_digests(name, n, d, modes, chunk,
     assert x.shape == (n, d), "fixture stream changed shape"
     state = _run(x, path, entry["chunk"])
     assert _digest(state) == entry["digests"][path], (
-        f"{path} ingest path drifted from the golden digest on {name}: "
-        f"if intentional, regenerate via --regen and explain in the PR")
+        f"{path} ingest path drifted from the golden digest on {name} "
+        f"(recorded under jax {doc.get('jax_version')}, running "
+        f"{jax.__version__}): if intentional, regenerate via --regen and "
+        f"explain the change")
     assert int(state.n_active) == entry[f"n_active_{path}"]
 
 

@@ -304,6 +304,21 @@ def test_worker_resume_step_false_not_error(worker):
 # process fleet == threaded fleet (the acceptance contract)
 # ---------------------------------------------------------------------------
 
+def test_process_placement_refused_on_an_accelerator(monkeypatch):
+    """A coordinator that runs on an accelerator holds its chip: worker
+    processes could not reach it, so process placement fails before any
+    worker is spawned."""
+    import jax
+    spawned = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(WorkerClient, "__init__",
+                        lambda self, *a, **k: spawned.append(a))
+    with pytest.raises(RuntimeError, match="placement='process'"):
+        FleetCoordinator(_cfg(_draw(64)), FleetConfig(placement="process"),
+                         RuntimeConfig(chunk=64))
+    assert spawned == []
+
+
 @pytest.mark.slow
 def test_process_fleet_matches_threaded_fleet(tmp_path):
     xs = _draw(768, 7)

@@ -121,9 +121,11 @@ def test_sparse_scorer_tracks_dense():
     dense = np.asarray(figmn.score_batch(cfg, state, jnp.asarray(held)))
     sparse = np.asarray(shortlist.score_batch_sparse(
         cfg, state, jnp.asarray(held)))
-    # truncation only ever drops tail mass ⇒ sparse ≤ dense, and the mean
+    # truncation only ever drops tail mass ⇒ sparse ≤ dense up to the
+    # order of the float32 reductions: 2 ulps of dense, and the mean
     # gap is the numerically-zero posterior tail
-    assert (sparse <= dense + 1e-5).all()
+    ulp = np.spacing(np.abs(dense))
+    assert (sparse <= dense + 2 * ulp).all(), np.max((sparse - dense) / ulp)
     assert abs(float(np.mean(sparse - dense))) < 1e-2
     # C = K reproduces the dense batched scorer to float tolerance
     full = np.asarray(shortlist.score_batch_sparse(
