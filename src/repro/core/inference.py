@@ -320,7 +320,7 @@ class FactorCache:
             = OrderedDict()
         self.hits = 0
         self.misses = 0
-        self._m_hits = self._m_misses = self._m_entries = None
+        self._m_hits = self._m_misses = None
         if registry is not None:
             self._m_hits = registry.counter(
                 "figmn_factor_cache_hits_total",
@@ -328,8 +328,6 @@ class FactorCache:
             self._m_misses = registry.counter(
                 "figmn_factor_cache_misses_total",
                 "eq. 27 factor bundles built fresh")
-            self._m_entries = registry.gauge(
-                "figmn_factor_cache_entries", "live cached factor bundles")
 
     def __len__(self) -> int:
         with self._lock:
@@ -363,15 +361,11 @@ class FactorCache:
             self._entries.move_to_end(key)
             while len(self._entries) > self.capacity:
                 self._entries.popitem(last=False)
-            if self._m_entries is not None:
-                self._m_entries.set(len(self._entries))
         return f
 
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
-            if self._m_entries is not None:
-                self._m_entries.set(0)
 
 
 # ---------------------------------------------------------------------------

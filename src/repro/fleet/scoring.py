@@ -50,6 +50,7 @@ sparse ingest path.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import deque
@@ -108,6 +109,7 @@ class _Pending(NamedTuple):
     t_submit: float    # perf_counter at caller submission (latency stamp)
     t_enq: float       # monotonic at enqueue (max-delay clock)
     deadline_t: Optional[float] = None   # monotonic cutoff (None = no SLO)
+    req: int = 0       # the frontend's request number (``serve.submit``)
 
 
 class _MicroBatcher:
@@ -123,7 +125,14 @@ class _MicroBatcher:
     ``_execute`` against the current snapshot, splits the rows back out,
     and resolves each future; per-request latency is observed from each
     request's own submit stamp, so queue wait + coalescing delay stay
-    inside the histogram the autoscaler watches."""
+    inside the histogram the autoscaler watches.
+
+    Every instant of the flush thread sits inside a span:
+    ``serve.wait_request`` (nothing queued), ``serve.coalesce`` (holding
+    queued work for the delay/batch policy) or ``serve.flush``, whose
+    children are ``serve.gather`` (deadline filter, concatenation),
+    ``serve.score``/``serve.predict`` (the device read) and
+    ``serve.resolve`` (per-answer slices, accounting, futures)."""
 
     def __init__(self, frontend: "ScoringFrontend", acfg: AdmissionConfig,
                  reg) -> None:
@@ -148,6 +157,11 @@ class _MicroBatcher:
         self._m_rejected = reg.counter(
             "figmn_serve_admission_rejected_total",
             "requests rejected by the admission queue cap")
+        self._m_queue_wait = reg.histogram(
+            "figmn_serve_queue_wait_seconds",
+            "admission-queue wait: submit to the pop of the request's "
+            "batch")
+        self._flushes = 0          # flush sequence number (flush thread)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="fleet-microbatch")
         self._thread.start()
@@ -158,8 +172,8 @@ class _MicroBatcher:
             return self._depth
 
     def submit(self, kind: str, xs, targets, return_var: bool,
-               t_submit: float, deadline_t: Optional[float] = None
-               ) -> "Future":
+               t_submit: float, deadline_t: Optional[float] = None,
+               req: int = 0) -> "Future":
         fe = self._fe
         xs = jnp.asarray(xs, fe.cfg.dtype)
         sig = inference._as_targets(targets) if kind == "predict" else None
@@ -188,7 +202,7 @@ class _MicroBatcher:
             key = (kind, sig, bool(return_var))
             self._queues.setdefault(key, deque()).append(
                 _Pending(xs, n, fut, t_submit, time.monotonic(),
-                         deadline_t))
+                         deadline_t, req))
             self._depth += 1
             self._m_depth.set(self._depth)
             self._cv.notify()
@@ -198,8 +212,11 @@ class _MicroBatcher:
         acfg = self.acfg
         while True:
             with self._cv:
-                while not self._closed and self._depth == 0:
-                    self._cv.wait()
+                if not self._closed and self._depth == 0:
+                    # nothing queued: the device idles by design here
+                    with span("serve.wait_request"):
+                        while not self._closed and self._depth == 0:
+                            self._cv.wait()
                 if self._closed and self._cancel:
                     # deterministic shutdown: every queued future resolves
                     # NOW, with CancelledError — no caller blocks forever
@@ -219,52 +236,63 @@ class _MicroBatcher:
                 wait = acfg.max_delay_s - (time.monotonic() - dq[0].t_enq)
                 if (len(dq) < acfg.max_batch and wait > 0
                         and not self._closed):
-                    self._cv.wait(timeout=wait)
+                    with span("serve.coalesce"):
+                        self._cv.wait(timeout=wait)
                     continue
                 batch = [dq.popleft()
                          for _ in range(min(len(dq), acfg.max_batch))]
+                t_pop = time.perf_counter()
                 if not dq:
                     del self._queues[key]
                 self._depth -= len(batch)
                 self._m_depth.set(self._depth)
-            self._flush(key, batch)
+            self._flush(key, batch, t_pop)
 
-    def _flush(self, key: tuple, batch: "List[_Pending]") -> None:
+    def _flush(self, key: tuple, batch: "List[_Pending]",
+               t_pop: float) -> None:
         kind, sig, return_var = key
         fe = self._fe
-        # expired deadlines resolve exceptionally BEFORE the dispatch —
-        # no device work is spent on an answer nobody is waiting for
-        now = time.monotonic()
-        live = []
-        for p in batch:
-            if p.deadline_t is not None and now > p.deadline_t:
-                p.future.set_exception(DeadlineExceeded(
-                    f"request deadline elapsed after "
-                    f"{now - p.t_enq:.4f}s in queue"))
-            else:
-                live.append(p)
-        batch = live
-        if not batch:
-            return
-        xs = (batch[0].xs if len(batch) == 1
-              else jnp.concatenate([p.xs for p in batch], axis=0))
-        self._m_batch_reqs.observe(len(batch))
-        self._m_batch_rows.observe(int(xs.shape[0]))
-        try:
-            out, published_t = fe._execute(kind, xs, sig, return_var)
-        except Exception as e:                   # pragma: no cover - defensive
-            for p in batch:
-                p.future.set_exception(e)
-            return
-        off = 0
-        for p in batch:
-            if return_var:
-                res = (out[0][off:off + p.n], out[1][off:off + p.n])
-            else:
-                res = out[off:off + p.n]
-            off += p.n
-            fe._finish(kind, p.n, p.t_submit, published_t)
-            p.future.set_result(res)
+        self._flushes += 1
+        with span("serve.flush", kind=kind, flush=self._flushes,
+                  req=batch[0].req) as sp:
+            with span("serve.gather"):
+                # expired deadlines resolve exceptionally BEFORE the
+                # dispatch — no device work is spent on an answer nobody
+                # is waiting for
+                now = time.monotonic()
+                live = []
+                for p in batch:
+                    self._m_queue_wait.observe(t_pop - p.t_submit)
+                    if p.deadline_t is not None and now > p.deadline_t:
+                        p.future.set_exception(DeadlineExceeded(
+                            f"request deadline elapsed after "
+                            f"{now - p.t_enq:.4f}s in queue"))
+                    else:
+                        live.append(p)
+                batch = live
+                if not batch:
+                    return
+                xs = (batch[0].xs if len(batch) == 1
+                      else jnp.concatenate([p.xs for p in batch], axis=0))
+            sp.set(requests=len(batch), rows=int(xs.shape[0]))
+            self._m_batch_reqs.observe(len(batch))
+            self._m_batch_rows.observe(int(xs.shape[0]))
+            try:
+                out, published_t = fe._execute(kind, xs, sig, return_var)
+            except Exception as e:               # pragma: no cover - defensive
+                for p in batch:
+                    p.future.set_exception(e)
+                return
+            with span("serve.resolve"):
+                off = 0
+                for p in batch:
+                    if return_var:
+                        res = (out[0][off:off + p.n], out[1][off:off + p.n])
+                    else:
+                        res = out[off:off + p.n]
+                    off += p.n
+                    fe._finish(kind, p.n, p.t_submit, published_t)
+                    p.future.set_result(res)
 
     def close(self, cancel_pending: bool = False) -> None:
         """Stop the flush thread.  Default drains (every queued future
@@ -325,6 +353,7 @@ class ScoringFrontend:
         self._pool = ThreadPoolExecutor(max_workers=max(int(workers), 1),
                                         thread_name_prefix="fleet-score")
         self.served = 0
+        self._reqs = itertools.count(1)     # request numbers (serve.submit)
         reg = registry or obs_registry.default_registry()
         # per-(version, targets) eq. 27 factor amortisation — invalidation
         # rides the version bump inside publish's atomic swap
@@ -332,7 +361,8 @@ class ScoringFrontend:
                                                   registry=reg)
         self.latency = reg.histogram(
             "figmn_serve_latency_seconds",
-            "request latency, submit to completion (queue wait included)")
+            "request latency, submit to completion: with admission "
+            "control, the queue wait plus the flush that answers it")
         self.staleness = reg.histogram(
             "figmn_serve_staleness_seconds",
             "serving-snapshot age at read time",
@@ -496,19 +526,22 @@ class ScoringFrontend:
     def _submit_async(self, kind: str, xs, targets, return_var: bool,
                       deadline_s: Optional[float]) -> "Future":
         t = time.perf_counter()
-        if self.batcher is not None:
-            deadline_t = (time.monotonic() + deadline_s
-                          if deadline_s is not None else None)
+        req = next(self._reqs)
+        with span("serve.submit", kind=kind, req=req):
+            if self.batcher is not None:
+                deadline_t = (time.monotonic() + deadline_s
+                              if deadline_s is not None else None)
 
-            def _try():
-                return self.batcher.submit(kind, xs, targets, return_var,
-                                           t, deadline_t)
+                def _try():
+                    return self.batcher.submit(kind, xs, targets,
+                                               return_var, t, deadline_t,
+                                               req)
 
-            if self.retry is not None:
-                return self.retry.call(_try, retry_on=AdmissionRejected)
-            return _try()
-        return self._pool.submit(self._serve, kind, xs, targets, t,
-                                 return_var, deadline_s)
+                if self.retry is not None:
+                    return self.retry.call(_try, retry_on=AdmissionRejected)
+                return _try()
+            return self._pool.submit(self._serve, kind, xs, targets, t,
+                                     return_var, deadline_s)
 
     def score(self, xs, deadline_s: Optional[float] = None) -> Array:
         """(N,) mixture log-densities under the current snapshot."""

@@ -244,24 +244,25 @@ class StreamRuntime:
                            xc_host: np.ndarray) -> None:
         idx = self.chunk_idx
         xh, replaced = xc_host, False
-        for h in self.chunk_hooks:
-            fn = getattr(h, "on_chunk_start", None)
-            if fn is not None:
-                rep = fn(idx, xh)
-                if rep is not None:
-                    xh, replaced = np.asarray(rep, np.float32), True
-        xh, n_bad = ingest.finite_guard(xh, self.rcfg.on_nonfinite)
-        if n_bad:
-            self.telemetry.add_quarantined(n_bad)
-            self._m_quarantined.inc(n_bad)
-            replaced = True
-        t0 = time.perf_counter()
-        n_in = int(xh.shape[0])
-        if n_in:
+        with span("stream.chunk_guard"):
+            for h in self.chunk_hooks:
+                fn = getattr(h, "on_chunk_start", None)
+                if fn is not None:
+                    rep = fn(idx, xh)
+                    if rep is not None:
+                        xh, replaced = np.asarray(rep, np.float32), True
+            xh, n_bad = ingest.finite_guard(xh, self.rcfg.on_nonfinite)
+            if n_bad:
+                self.telemetry.add_quarantined(n_bad)
+                self._m_quarantined.inc(n_bad)
+                replaced = True
+            t0 = time.perf_counter()
+            n_in = int(xh.shape[0])
             # the all-finite, un-replaced fast path reuses the device copy
             # already in flight — the guard costs one host isfinite sweep
             xd = (jax.device_put(jnp.asarray(xh, self.cfg.dtype))
-                  if replaced else xc_dev)
+                  if n_in and replaced else xc_dev)
+        if n_in:
             self._ingest_chunk(xd, xh)
         for h in self.chunk_hooks:
             fn = getattr(h, "on_chunk_end", None)
@@ -279,8 +280,9 @@ class StreamRuntime:
         # the host on the device and serialise the double-buffered feed.
         need_stats = self.detector is not None or rc.telemetry_anomaly
         t0 = time.perf_counter()
-        n_created0 = int(self.state.n_created)
-        formed = bool(jnp.any(self.state.active))
+        with span("stream.chunk_pull"):
+            n_created0 = int(self.state.n_created)
+            formed = bool(jnp.any(self.state.active))
         path = self.path
         if path == "vmem" and not formed:
             path = "scan"            # kernel cannot create the first slot
@@ -338,30 +340,32 @@ class StreamRuntime:
             if alarm:
                 self._respond_to_drift()
 
-        # the active_k pull doubles as the latency fence: it blocks on this
-        # chunk's (donated, async-dispatched) fit, so latency_s includes
-        # the device compute on every path — this is the ONE per-chunk
-        # device sync the telemetry schema requires (chunk-granular
-        # active_k/latency records cannot be deferred without losing them)
-        active_k = int(self.state.n_active)
-        latency = time.perf_counter() - t0
-        self.telemetry.record(telemetry.ChunkMetrics(
-            idx=self.chunk_idx, n_points=int(xc.shape[0]),
-            active_k=active_k,
-            created=int(self.state.n_created) - n_created0,
-            mean_ll=mean_ll, novelty_rate=novelty_rate,
-            drift_score=float(drift_score), drift_alarm=alarm,
-            path=path, latency_s=latency))
-        self._m_chunk_s.observe(latency)
-        self._m_meas_s.set(latency)
-        if self.dispatch.per_point_s is not None:
-            # predicted for THIS chunk size — a tail chunk is smaller
-            self._m_pred_s.set(self.dispatch.per_point_s
-                               * int(xc.shape[0]))
-        self._m_points.inc(int(xc.shape[0]))
-        self._m_active.set(active_k)
-        if alarm:
-            self._m_drift.inc()
+        with span("stream.chunk_fence"):
+            # the active_k pull doubles as the latency fence: it blocks on
+            # this chunk's (donated, async-dispatched) fit, so latency_s
+            # includes the device compute on every path — this is the ONE
+            # per-chunk device sync the telemetry schema requires
+            # (chunk-granular active_k/latency records cannot be deferred
+            # without losing them)
+            active_k = int(self.state.n_active)
+            latency = time.perf_counter() - t0
+            self.telemetry.record(telemetry.ChunkMetrics(
+                idx=self.chunk_idx, n_points=int(xc.shape[0]),
+                active_k=active_k,
+                created=int(self.state.n_created) - n_created0,
+                mean_ll=mean_ll, novelty_rate=novelty_rate,
+                drift_score=float(drift_score), drift_alarm=alarm,
+                path=path, latency_s=latency))
+            self._m_chunk_s.observe(latency)
+            self._m_meas_s.set(latency)
+            if self.dispatch.per_point_s is not None:
+                # predicted for THIS chunk size — a tail chunk is smaller
+                self._m_pred_s.set(self.dispatch.per_point_s
+                                   * int(xc.shape[0]))
+            self._m_points.inc(int(xc.shape[0]))
+            self._m_active.set(active_k)
+            if alarm:
+                self._m_drift.inc()
         self.chunk_idx += 1
 
         if (rc.lifecycle is not None and rc.lifecycle.every > 0
