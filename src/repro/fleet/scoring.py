@@ -33,12 +33,14 @@ Serving COST (ROADMAP item 4's amortisation layer):
 * With an ``AdmissionConfig``, async requests flow through a micro-batcher
   (the slot/queue pattern of ``serve.engine``): compatible queued requests
   — same kind, same targets signature, same return_var — coalesce into ONE
-  device dispatch under a max-delay + max-batch policy.  Each request's
-  latency is still observed from its OWN submit stamp (queue wait + delay
-  + batched compute), so the histogram contract the autoscaler consumes is
-  unchanged.  Queue depth and coalesced batch size export through the obs
-  registry; a full queue rejects at submission (admission control, not
-  silent unbounded buffering).
+  device dispatch under a max-delay + max-batch policy, and ONE
+  device-to-host copy of its output answers them all (each future
+  resolves to a read-only NumPy view of the flush's host buffer).  Each
+  request's latency is still observed from its OWN submit stamp (queue
+  wait + delay + batched compute), so the histogram contract the
+  autoscaler consumes is unchanged.  Queue depth and coalesced batch
+  size export through the obs registry; a full queue rejects at
+  submission (admission control, not silent unbounded buffering).
 
 Scoring cost: the dense read is one (B, K) Mahalanobis sweep over the full
 (K, D, D) snapshot — O(B·K·D²).  With a shortlist width C (cfg.shortlist_c
@@ -57,7 +59,9 @@ from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import inference, shortlist
 from repro.core.types import Array, FIGMNConfig, FIGMNState
@@ -122,17 +126,22 @@ class _MicroBatcher:
     instance so it needs no key slot — and a class flushes when full
     (``max_batch`` requests) or aged (``max_delay_s`` since its oldest
     entry).  The flush concatenates the member batches, runs ONE
-    ``_execute`` against the current snapshot, splits the rows back out,
-    and resolves each future; per-request latency is observed from each
-    request's own submit stamp, so queue wait + coalescing delay stay
-    inside the histogram the autoscaler watches.
+    ``_execute`` against the current snapshot, copies its output to the
+    host ONCE, splits the rows back out of that host buffer, and resolves
+    each future; per-request latency is observed from each request's own
+    submit stamp, so queue wait + coalescing delay stay inside the
+    histogram the autoscaler watches.  A queued request's future resolves
+    to a host ``numpy.ndarray`` (a pair of them under return_var),
+    bit-identical to the synchronous read: a read-only view of its flush's
+    host buffer, so no caller can alter another's answer.  An empty
+    request resolves inline with the synchronous read's (0, ·) arrays.
 
     Every instant of the flush thread sits inside a span:
     ``serve.wait_request`` (nothing queued), ``serve.coalesce`` (holding
     queued work for the delay/batch policy) or ``serve.flush``, whose
     children are ``serve.gather`` (deadline filter, concatenation),
     ``serve.score``/``serve.predict`` (the device read) and
-    ``serve.resolve`` (per-answer slices, accounting, futures)."""
+    ``serve.resolve`` (the flush's one host copy, accounting, futures)."""
 
     def __init__(self, frontend: "ScoringFrontend", acfg: AdmissionConfig,
                  reg) -> None:
@@ -161,6 +170,10 @@ class _MicroBatcher:
             "figmn_serve_queue_wait_seconds",
             "admission-queue wait: submit to the pop of the request's "
             "batch")
+        self._m_resolve_copies = reg.counter(
+            "figmn_serve_resolve_copies_total",
+            "device-to-host copies made by resolve: one per dispatched "
+            "flush")
         self._flushes = 0          # flush sequence number (flush thread)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="fleet-microbatch")
@@ -284,12 +297,22 @@ class _MicroBatcher:
                     p.future.set_exception(e)
                 return
             with span("serve.resolve"):
+                # ONE device-to-host copy per flush (both members of a
+                # return_var pair in one call); every answer is then a
+                # NumPy view of that read-only buffer — no device program
+                # and no transfer per answer
+                try:
+                    host = jax.device_get(out)
+                except Exception as e:
+                    for p in batch:
+                        p.future.set_exception(e)
+                    return
+                self._m_resolve_copies.inc()
                 off = 0
                 for p in batch:
-                    if return_var:
-                        res = (out[0][off:off + p.n], out[1][off:off + p.n])
-                    else:
-                        res = out[off:off + p.n]
+                    rows = slice(off, off + p.n)
+                    res = ((host[0][rows], host[1][rows]) if return_var
+                           else host[rows])
                     off += p.n
                     fe._finish(kind, p.n, p.t_submit, published_t)
                     p.future.set_result(res)
@@ -549,13 +572,15 @@ class ScoringFrontend:
                            deadline_s=deadline_s)
 
     def score_async(self, xs, deadline_s: Optional[float] = None
-                    ) -> "Future[Array]":
+                    ) -> "Future[Array | np.ndarray]":
         """Queue a score; the returned future resolves off the caller's
         thread, against whichever snapshot is current when it runs.  With
         admission control configured, compatible queued scores coalesce
-        into one device dispatch; a request still queued when its
-        ``deadline_s`` elapses resolves with DeadlineExceeded instead of
-        spending device work."""
+        into one device dispatch and the future resolves to a read-only
+        host ``numpy.ndarray`` (bit-identical to ``score``); a request
+        still queued when its ``deadline_s`` elapses resolves with
+        DeadlineExceeded instead of spending device work.  Without it the
+        worker pool resolves the future to ``score``'s device array."""
         return self._submit_async("score", xs, None, False, deadline_s)
 
     def predict(self, xs, targets, return_var: bool = False,
@@ -575,14 +600,18 @@ class ScoringFrontend:
                            return_var, deadline_s=deadline_s)
 
     def predict_async(self, xs, targets, return_var: bool = False,
-                      deadline_s: Optional[float] = None) -> "Future":
+                      deadline_s: Optional[float] = None
+                      ) -> "Future[Array | np.ndarray]":
         """Queue a conditional read; resolves off the caller's thread
         against whichever snapshot is current when it runs — the serving
         front door keeps answering eq. 27 while the coordinator is mid
         ingest.  With admission control configured, compatible queued
         requests (same targets, same return_var) coalesce into one device
-        dispatch; expired deadlines resolve with DeadlineExceeded before
-        any device work."""
+        dispatch and the future resolves to read-only host
+        ``numpy.ndarray``s (a (mean, var) pair under return_var),
+        bit-identical to ``predict``; expired deadlines resolve with
+        DeadlineExceeded before any device work.  Without it the worker
+        pool resolves the future to ``predict``'s device arrays."""
         return self._submit_async("predict", xs, targets, return_var,
                                   deadline_s)
 

@@ -9,6 +9,9 @@ and the host-sync children of an ingest chunk.
   number of its oldest member;
 * ``figmn_serve_queue_wait_seconds`` counts exactly the queued requests:
   synchronous reads add nothing;
+* ``figmn_serve_resolve_copies_total`` counts one device-to-host copy per
+  flush that dispatched a read; a flush whose requests all expired at the
+  deadline filter adds nothing;
 * with tracing off the answers are bit-identical and no span is recorded;
 * every ``stream.ingest_chunk`` holds ``stream.chunk_guard``,
   ``stream.chunk_pull`` and ``stream.chunk_fence``, in that order.
@@ -19,7 +22,8 @@ import pytest
 
 from repro.core import figmn
 from repro.core.types import FIGMNConfig
-from repro.fleet.scoring import AdmissionConfig, ScoringFrontend
+from repro.fleet.scoring import (AdmissionConfig, DeadlineExceeded,
+                                 ScoringFrontend)
 from repro.obs import registry as obs_registry
 from repro.obs import trace as obs_trace
 from repro.stream import RuntimeConfig, StreamRuntime
@@ -137,6 +141,36 @@ def test_queue_wait_counts_exactly_the_queued_requests(fitted):
     lat = reg.histogram("figmn_serve_latency_seconds")
     assert lat.count == 2 * N_ASYNC + 2
     assert wait.sum <= lat.sum
+
+
+def test_resolve_copies_one_per_dispatched_flush(fitted):
+    cfg, state, x = fitted
+    reg = obs_registry.Registry()
+    tracer = obs_trace.enable(capacity=1 << 14)
+    fe = ScoringFrontend(cfg, registry=reg,
+                         admission=AdmissionConfig(max_batch=5,
+                                                   max_delay_s=0.01))
+    fe.publish(state)
+    # deadlines that elapse in the queue: dropped before any dispatch
+    late = [fe.score_async(x[i:i + 1], deadline_s=1e-6) for i in range(3)]
+    for f in late:
+        with pytest.raises(DeadlineExceeded):
+            f.result(timeout=60)
+    futs = [fe.score_async(x[i:i + 1]) for i in range(N_ASYNC)]
+    futs += [fe.predict_async(x[i:i + 1, :4], [4], return_var=True)
+             for i in range(N_ASYNC)]
+    for f in futs:
+        f.result(timeout=60)
+    fe.close()
+    spans = tracer.spans()
+    flushes = [s for s in spans if s.name == "serve.flush"]
+    dispatched = [f for f in flushes if any(
+        c.name in ("serve.score", "serve.predict")
+        for c in _children(spans, f))]
+    assert len(dispatched) < len(flushes)      # the deadline flush
+    assert len(dispatched) >= 2 * N_ASYNC // 5
+    assert reg.counter("figmn_serve_resolve_copies_total").value == \
+        len(dispatched)
 
 
 def test_tracing_off_answers_bit_identical_and_no_spans(fitted):

@@ -14,6 +14,9 @@ Contracts pinned here:
     reads;
   * micro-batched async answers equal their sync twins and the coalescing
     metrics move; a full admission queue rejects at submission;
+  * a micro-batched answer is a read-only host ``numpy.ndarray`` view of
+    its flush's one host copy, bit-identical to the synchronous read, for
+    score, predict and both members of predict(return_var=True);
   * B=0 through score / predict / predict_async returns well-formed
     (0, ·) outputs on ALL THREE frontends (StreamRuntime, ScoringFrontend
     via FleetCoordinator, Mixture) — one contract;
@@ -268,6 +271,66 @@ def test_admission_queue_cap_rejects():
         assert np.asarray(f.result(timeout=5)).shape == (1, 1)
 
 
+# (kind, targets, return_var) of each synchronous/async twin
+_READS = {"score": ("score", None, False),
+          "predict": ("predict", [4], False),
+          "predict_var": ("predict", [4], True)}
+
+
+def _coalesced_answers(read, n=12, batch=4):
+    """``n`` one-row async answers of ``read`` through a micro-batcher
+    whose every flush holds ``batch`` consecutive requests (the delay is
+    far longer than the test), and the synchronous reads of the same
+    row groups — each answer's twin."""
+    from repro.fleet.scoring import AdmissionConfig as AC
+    from repro.fleet.scoring import ScoringFrontend
+    kind, targets, return_var = _READS[read]
+    cfg, state, x = _fitted(seed=4)
+    q = x[:n] if kind == "score" else x[:n, :4]
+    reg = obs_registry.Registry()
+    fe = ScoringFrontend(cfg, registry=reg,
+                         admission=AC(max_batch=batch, max_delay_s=60.0))
+    fe.publish(state)
+    groups = [q[i:i + batch] for i in range(0, n, batch)]
+    if kind == "score":
+        sync = [fe.score(g) for g in groups]
+        futs = [fe.score_async(q[i:i + 1]) for i in range(n)]
+    else:
+        sync = [fe.predict(g, targets, return_var=return_var)
+                for g in groups]
+        futs = [fe.predict_async(q[i:i + 1], targets,
+                                 return_var=return_var) for i in range(n)]
+    got = [f.result(timeout=60) for f in futs]
+    fe.close()
+    reqs = reg.histogram("figmn_serve_coalesced_requests")
+    assert reqs.count == n // batch and reqs.sum == n
+    twins = ([np.concatenate(m) for m in zip(*sync)] if return_var
+             else [np.concatenate(sync)])
+    return twins, got, return_var
+
+
+@pytest.mark.parametrize("read", sorted(_READS))
+def test_microbatch_answers_are_host_arrays_bit_identical_to_sync(read):
+    twins, got, return_var = _coalesced_answers(read)
+    members = list(zip(*got)) if return_var else [got]
+    assert len(members) == len(twins)
+    for answers, twin in zip(members, twins):
+        for a in answers:
+            assert type(a) is np.ndarray and a.shape[0] == 1
+        np.testing.assert_array_equal(np.concatenate(answers), twin)
+
+
+@pytest.mark.parametrize("read", sorted(_READS))
+def test_microbatch_answers_are_read_only(read):
+    _, got, return_var = _coalesced_answers(read)
+    answers = [m for pair in got for m in pair] if return_var else got
+    before = [a.copy() for a in answers]
+    with pytest.raises(ValueError, match="read-only"):
+        answers[0][...] = 0.0
+    for a, b in zip(answers, before):
+        np.testing.assert_array_equal(a, b)
+
+
 # ---------------------------------------------------------------------------
 # B=0: one empty-batch contract across all three frontends
 # ---------------------------------------------------------------------------
@@ -297,6 +360,8 @@ def test_empty_batch_contract_all_frontends():
     assert fc.predict(e4, [4]).shape == (0, 1)
     assert fc.score_async(e5).result(timeout=10).shape == (0,)
     assert fc.predict_async(e4, [4]).result(timeout=10).shape == (0, 1)
+    m, v = fc.predict_async(e4, [4], return_var=True).result(timeout=10)
+    assert m.shape == (0, 1) and v.shape == (0, 1)
     fc.close()
 
     # frontend 3: the Mixture facade (and the raw query layer)
